@@ -160,16 +160,14 @@ def lower_bound_energies(durations, occupancy, cands: Sequence[Candidate], *,
     under every policy (required leakage and accesses are unavoidable;
     switching and timer/retention leakage are >= 0), which makes it safe
     for pruning."""
-    import jax
-
-    from repro.kernels.bank_energy import bank_activity_stats, bank_energy_np
+    from repro.kernels.bank_energy import (bank_activity_stats,
+                                           bank_energy_np, resolve_backend)
     p_leak, e_r, e_w, _, _, _ = _characteristics(cands)
     usable = np.array([c.usable_bytes for c in cands])
     nbanks = np.array([float(c.banks) for c in cands])
     d = np.asarray(durations, np.float64)
     o = np.asarray(occupancy, np.float64)
-    if backend == "numpy" or (backend == "auto"
-                              and jax.default_backend() != "tpu"):
+    if resolve_backend(backend) == "numpy":
         # toggles are dead weight here — bank-seconds only
         seconds = bank_energy_np(d, o, usable, nbanks, toggles=False)[:, 0]
     else:

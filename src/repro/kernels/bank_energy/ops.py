@@ -37,7 +37,9 @@ from repro.kernels.bank_energy.ref import (bank_energy_np, bank_energy_ref,
 KIB = 1024.0
 
 
-def _resolve(backend: str) -> str:
+def resolve_backend(backend: str) -> str:
+    """"auto" is a pure platform switch: the Pallas kernel on a TPU, the
+    float64 numpy path anywhere else."""
     if backend != "auto":
         return backend
     return "pallas" if jax.default_backend() == "tpu" else "numpy"
@@ -73,7 +75,7 @@ def _bank_activity_stats_jit(durations, occupancy, usable, nbanks, *,
 def bank_activity_stats(durations, occupancy, usable, nbanks, *,
                         backend: str = "auto", block_s: int = 2048):
     """(C, 2): [active bank-seconds, on/off transition count] per candidate."""
-    backend = _resolve(backend)
+    backend = resolve_backend(backend)
     if backend == "numpy":
         return bank_energy_np(durations, occupancy, usable, nbanks)
     return _bank_activity_stats_jit(durations, occupancy, usable, nbanks,
@@ -103,7 +105,7 @@ def exact_bank_stats(durations, occupancy, usable, nbanks, threshold, *,
     """(C, 5) exact idle-run stats per candidate: [active bank-seconds,
     idle runs >= threshold, their seconds, idle runs < threshold, their
     seconds]. See `exact_bank_stats_np` for the reference semantics."""
-    backend = _resolve(backend)
+    backend = resolve_backend(backend)
     if backend == "numpy":
         return exact_bank_stats_np(durations, occupancy, usable, nbanks,
                                    threshold)

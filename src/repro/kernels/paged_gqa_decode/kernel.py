@@ -92,8 +92,8 @@ def _paged_decode_quant_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (group, d)
         # in-register dequant: int8 payload * per-row scale
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0, 0][:, None]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         tpos = t_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -142,7 +142,7 @@ def paged_gqa_decode_quant_kernel(q: jax.Array, k_pages: jax.Array,
 
     def sc_map(b, kh, it, lens, pt):
         # per-page scales ride the same prefetched page-table indirection
-        return (pt[b, it], kh, 0)
+        return (pt[b, it], kh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -151,8 +151,8 @@ def paged_gqa_decode_quant_kernel(q: jax.Array, k_pages: jax.Array,
             pl.BlockSpec((1, 1, group, d), q_map),
             pl.BlockSpec((1, 1, ps, d), kv_map),
             pl.BlockSpec((1, 1, ps, d), kv_map),
-            pl.BlockSpec((1, 1, ps), sc_map),
-            pl.BlockSpec((1, 1, ps), sc_map),
+            pl.BlockSpec((1, 1, 1, ps), sc_map),
+            pl.BlockSpec((1, 1, 1, ps), sc_map),
         ],
         out_specs=pl.BlockSpec((1, 1, group, d), q_map),
         scratch_shapes=[
@@ -167,7 +167,10 @@ def paged_gqa_decode_quant_kernel(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, K, group, d), q.dtype),
         interpret=interpret,
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32),
-      qg, k_pages, v_pages, k_scale, v_scale)
+      # scales as (N, K, 1, ps): a (1, ps) block then equals the array's
+      # last two dims, as the TPU's (8, 128) tiling rule requires
+      qg, k_pages, v_pages, k_scale.reshape(N, K, 1, ps),
+      v_scale.reshape(N, K, 1, ps))
     return out.reshape(B, H, d)
 
 

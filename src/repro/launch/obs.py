@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import reduced, resolve_arch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.obs import Telemetry, export_chrome_trace
 from repro.serve import PagedContinuousBatcher, Request
@@ -56,7 +57,7 @@ def run_serve(args, meter=None) -> tuple:
     cb = PagedContinuousBatcher(
         model, params, num_slots=args.slots, page_size=args.page_size,
         num_pages=args.num_pages, chunk_steps=args.chunk_steps,
-        attn_backend="ref", prefix_cache=args.prefix, telemetry=tel,
+        attn_backend="auto", prefix_cache=args.prefix, telemetry=tel,
         meter=meter)
     for s, toks in zip(specs, tokens):
         tenant = None if s.prefix_id is None else f"tenant{s.prefix_id}"
@@ -144,6 +145,7 @@ def run_energy(args) -> None:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     sub = ap.add_subparsers(dest="cmd", required=True)
     for name in ("report", "export"):

@@ -19,6 +19,7 @@ import json
 
 from repro.configs import resolve_arch
 from repro.core.explorer import MIB, min_capacity_mib, sweep
+from repro.launch.compile_cache import setup_compile_cache
 from repro.traffic.campaign import DEFAULT_BANKS, CampaignReport, run_campaign
 from repro.traffic.controller import ControllerConfig, ForecastConfig
 from repro.traffic.generators import LengthModel
@@ -83,6 +84,7 @@ def build_report_dict(report: CampaignReport) -> dict:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--model", nargs="+", default=["dsr1d-qwen-1.5b"],
                     help="arch name(s); '_' spellings accepted "

@@ -16,12 +16,14 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced as make_reduced
 from repro.data import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.optim import AdamW, cosine_with_warmup
 from repro.train import LoopConfig, TrainLoop
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")

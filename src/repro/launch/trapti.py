@@ -18,6 +18,7 @@ from repro.core.energy import assemble_energy
 from repro.core.explorer import min_capacity_mib, sweep
 from repro.core.sensitivity import policy_sensitivity
 from repro.core.workload import build_decode_graph, build_graph
+from repro.launch.compile_cache import setup_compile_cache
 from repro.sim.accelerator import baseline_accelerator, multilevel_accelerator
 from repro.sim.engine import find_min_sram, simulate
 from repro.sim.pss import simulate_decode
@@ -26,6 +27,7 @@ MIB = 2**20
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="dsr1d-qwen-1.5b",
                     help=f"one of {list_archs()}")

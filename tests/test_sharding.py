@@ -5,26 +5,22 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_arch, reduced
+from repro.launch.mesh import make_host_mesh
 from repro.models.common import PTpl
 from repro.models.meshctx import constrain, current_mesh, use_mesh
 from repro.models.sharding import (SERVE_RULES, TRAIN_RULES, batch_spec,
                                    spec_for)
 
 
-def _mesh(shape=(2, 2), axes=("data", "model")):
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:1] * 0 or None) \
-        if False else jax.make_mesh((1, 1), axes)
-
-
 def test_spec_for_divisible_dims():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     # weight (D, F): embed -> data, mlp -> model (both divisible by 1)
     s = spec_for(("embed", "mlp"), (64, 128), mesh, TRAIN_RULES)
     assert s == P("data", "model")
 
 
 def test_spec_for_indivisible_falls_back_to_replicate():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     # simulate a 16-way axis via a fake mesh-like object
     class FakeMesh:
         shape = {"data": 16, "model": 16}
@@ -57,7 +53,7 @@ def test_constrain_is_noop_without_mesh():
 
 
 def test_constrain_drops_missing_axes_and_indivisible():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     with use_mesh(mesh):
         x = jnp.ones((4, 4))
         # "pod" doesn't exist on this mesh; must not raise
@@ -70,7 +66,7 @@ def test_template_shardings_cover_full_tree():
     from repro.models.sharding import template_shardings
     cfg = reduced(get_arch("qwen2-7b"))
     m = build_model(cfg, compute_dtype=jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     tpl = m.template()
     sh = template_shardings(tpl, mesh, TRAIN_RULES)
     n_tpl = len(jax.tree.leaves(tpl, is_leaf=lambda x: isinstance(x, PTpl)))
@@ -81,7 +77,7 @@ def test_template_shardings_cover_full_tree():
 def test_cache_specs_structure_matches_cache():
     from repro.models.transformer import cache_specs, init_cache
     cfg = reduced(get_arch("recurrentgemma-2b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     cache = jax.eval_shape(lambda: init_cache(cfg, 4, 64))
     specs = cache_specs(cfg, 4, 64, mesh)
     assert jax.tree.structure(

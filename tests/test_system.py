@@ -99,3 +99,27 @@ def test_serve_batch_entries_independent():
         np.concatenate([p1, p2]), jnp.int32)})
     solo = srv.generate({"tokens": jnp.asarray(p1, jnp.int32)})
     np.testing.assert_array_equal(both["tokens"][0], solo["tokens"][0])
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir_from_env_else_checkout(tmp_path, monkeypatch,
+                                                  env_dir):
+    """The persistent compile cache goes to $JAX_COMPILATION_CACHE_DIR when
+    set, else to the fixed `<checkout>/.jax_cache`, which git ignores."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import setup_compile_cache
+    root = Path(__file__).resolve().parents[1]
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(root / ".jax_cache")
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert setup_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
