@@ -190,75 +190,82 @@ def evaluate_candidates(durations, occupancy, cands: Sequence[Candidate], *,
     `always_evaluate` lists indices exempt from pruning (e.g. a sweep's
     delta baselines)."""
     from repro.kernels.bank_energy import exact_bank_stats
+    from repro.obs.telemetry import host_span
     cands = list(cands)
     n = len(cands)
-    d = np.asarray(durations, np.float64)
-    occ = np.asarray(occupancy, np.float64)
-    total_time = float(d.sum())
+    # wall-clock spans on the profiler's clock: the host's share of a sweep
+    # (characteristics, pruning, then the energy arithmetic) beside the
+    # bank-statistics kernel and its readback
+    with host_span("stage2.prepare", candidates=n):
+        d = np.asarray(durations, np.float64)
+        occ = np.asarray(occupancy, np.float64)
+        total_time = float(d.sum())
 
-    p_leak, e_r, e_w, e_sw_j, break_even, area = _characteristics(cands)
-    e_dyn = n_reads * e_r + n_writes * e_w
-    nbanks_f = np.array([float(c.banks) for c in cands])
-    total_bank_seconds = nbanks_f * total_time
+        p_leak, e_r, e_w, e_sw_j, break_even, area = _characteristics(cands)
+        e_dyn = n_reads * e_r + n_writes * e_w
+        nbanks_f = np.array([float(c.banks) for c in cands])
+        total_bank_seconds = nbanks_f * total_time
 
-    lb = np.full(n, -np.inf)
-    evaluated = np.ones(n, bool)
-    if prune and n > 1:
-        lb = lower_bound_energies(d, occ, cands, n_reads=n_reads,
-                                  n_writes=n_writes, backend=backend)
-        incumbent_i = int(np.argmin(lb))
-        inc = evaluate_candidates(d, occ, [cands[incumbent_i]],
-                                  n_reads=n_reads, n_writes=n_writes,
-                                  backend=backend, block_s=block_s)
-        cutoff = float(inc.e_total[0]) * (1.0 + prune_margin)
-        evaluated = lb <= cutoff
-        evaluated[incumbent_i] = True
-        for i in (always_evaluate or ()):
-            evaluated[i] = True
+        lb = np.full(n, -np.inf)
+        evaluated = np.ones(n, bool)
+        if prune and n > 1:
+            lb = lower_bound_energies(d, occ, cands, n_reads=n_reads,
+                                      n_writes=n_writes, backend=backend)
+            incumbent_i = int(np.argmin(lb))
+            inc = evaluate_candidates(d, occ, [cands[incumbent_i]],
+                                      n_reads=n_reads, n_writes=n_writes,
+                                      backend=backend, block_s=block_s)
+            cutoff = float(inc.e_total[0]) * (1.0 + prune_margin)
+            evaluated = lb <= cutoff
+            evaluated[incumbent_i] = True
+            for i in (always_evaluate or ()):
+                evaluated[i] = True
 
-    need = [i for i in range(n)
-            if evaluated[i] and cands[i].policy != "none"]
-    stats = np.zeros((n, 5))
-    if need and len(d):
-        usable = np.array([cands[i].usable_bytes for i in need])
-        nb = np.array([float(cands[i].banks) for i in need])
-        th = np.array([cands[i].min_gate_multiple for i in need]) \
-            * break_even[need]
-        stats[need] = np.asarray(
-            exact_bank_stats(d, occ, usable, nb, th, backend=backend,
-                             block_s=block_s), np.float64)
+        need = [i for i in range(n)
+                if evaluated[i] and cands[i].policy != "none"]
+    with host_span("stage2.kernel", candidates=n):
+        stats = np.zeros((n, 5))
+        if need and len(d):
+            usable = np.array([cands[i].usable_bytes for i in need])
+            nb = np.array([float(cands[i].banks) for i in need])
+            th = np.array([cands[i].min_gate_multiple for i in need]) \
+                * break_even[need]
+            stats[need] = np.asarray(
+                exact_bank_stats(d, occ, usable, nb, th, backend=backend,
+                                 block_s=block_s), np.float64)
 
-    pol = np.array([POLICIES.index(c.policy) for c in cands])
-    is_none, is_gate, is_drowsy = pol == 0, pol == 1, pol == 2
+    with host_span("stage2.energies", candidates=n):
+        pol = np.array([POLICIES.index(c.policy) for c in cands])
+        is_none, is_gate, is_drowsy = pol == 0, pol == 1, pol == 2
 
-    act_s = stats[:, _ACT_S]
-    n_off = np.where(is_none, 0.0, stats[:, _N_LONG])
-    off_s = stats[:, _LONG_S]
-    n_short = stats[:, _N_SHORT]
-    short_s = stats[:, _SHORT_S]
+        act_s = stats[:, _ACT_S]
+        n_off = np.where(is_none, 0.0, stats[:, _N_LONG])
+        off_s = stats[:, _LONG_S]
+        n_short = stats[:, _N_SHORT]
+        short_s = stats[:, _SHORT_S]
 
-    # leakage: none -> all banks all the time; gate -> everything except
-    # gated (long-idle) runs; drowsy -> ON while required + retention
-    # fraction during short idles
-    e_leak_on = np.where(
-        is_none, p_leak * total_bank_seconds,
-        np.where(is_gate, p_leak * (total_bank_seconds - off_s),
-                 p_leak * act_s))
-    e_leak_drowsy = np.where(is_drowsy,
-                             p_leak * DROWSY_LEAK_FRACTION * short_s, 0.0)
-    e_sw = np.where(
-        is_none, 0.0,
-        n_off * e_sw_j + np.where(
-            is_drowsy, n_short * e_sw_j * DROWSY_SWITCH_FRACTION, 0.0))
-    n_drowsy = np.where(is_drowsy, n_short, 0.0)
-    gated = np.where(is_none, 0.0, off_s)
+        # leakage: none -> all banks all the time; gate -> everything except
+        # gated (long-idle) runs; drowsy -> ON while required + retention
+        # fraction during short idles
+        e_leak_on = np.where(
+            is_none, p_leak * total_bank_seconds,
+            np.where(is_gate, p_leak * (total_bank_seconds - off_s),
+                     p_leak * act_s))
+        e_leak_drowsy = np.where(is_drowsy,
+                                 p_leak * DROWSY_LEAK_FRACTION * short_s, 0.0)
+        e_sw = np.where(
+            is_none, 0.0,
+            n_off * e_sw_j + np.where(
+                is_drowsy, n_short * e_sw_j * DROWSY_SWITCH_FRACTION, 0.0))
+        n_drowsy = np.where(is_drowsy, n_short, 0.0)
+        gated = np.where(is_none, 0.0, off_s)
 
-    out = CandidateEnergies(
-        candidates=cands, e_dyn=e_dyn, e_leak=e_leak_on + e_leak_drowsy,
-        e_sw=e_sw, e_leak_on=e_leak_on, e_leak_drowsy=e_leak_drowsy,
-        n_off=n_off.astype(np.int64), n_drowsy=n_drowsy.astype(np.int64),
-        gated_bank_seconds=gated, total_bank_seconds=total_bank_seconds,
-        area_mm2=area, evaluated=evaluated, lower_bound=lb)
+        out = CandidateEnergies(
+            candidates=cands, e_dyn=e_dyn, e_leak=e_leak_on + e_leak_drowsy,
+            e_sw=e_sw, e_leak_on=e_leak_on, e_leak_drowsy=e_leak_drowsy,
+            n_off=n_off.astype(np.int64), n_drowsy=n_drowsy.astype(np.int64),
+            gated_bank_seconds=gated, total_bank_seconds=total_bank_seconds,
+            area_mm2=area, evaluated=evaluated, lower_bound=lb)
     # pruned rows report their lower bound so ranking stays informative
     pruned = ~evaluated
     if pruned.any():

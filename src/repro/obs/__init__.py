@@ -5,19 +5,18 @@ from repro.obs.perfetto import (bank_state_events, chrome_trace_events,
                                 counter_integral, energy_counter_total,
                                 export_chrome_trace)
 from repro.obs.slo import (RequestTimeline, SLOSummary, SLOTracker,
-                           attach_energy_percentiles, percentile_summary,
-                           summarize_histograms)
+                           attach_energy_percentiles, summarize_histograms)
 from repro.obs.telemetry import (DEFAULT_BUCKETS, LATENCY_BUCKETS, Counter,
                                  Gauge, Histogram, Span, Telemetry,
-                                 default_registry, log_bucket_edges,
-                                 noop_registry)
+                                 default_registry, host_span,
+                                 log_bucket_edges, noop_registry)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Span", "Telemetry",
     "DEFAULT_BUCKETS", "LATENCY_BUCKETS", "log_bucket_edges",
-    "default_registry", "noop_registry",
+    "default_registry", "noop_registry", "host_span",
     "RequestTimeline", "SLOSummary", "SLOTracker",
-    "percentile_summary", "summarize_histograms",
+    "summarize_histograms",
     "chrome_trace_events", "counter_integral", "export_chrome_trace",
     "BankEnergyMeter", "MeterReport", "attach_energy_percentiles",
     "bank_state_events", "energy_counter_total",

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List
 
 import numpy as np
 
@@ -155,30 +155,6 @@ class SLOTracker:
 
     def summary(self) -> SLOSummary:
         return summarize_histograms(self.ttft, self.tbt, self.e2e)
-
-
-def percentile_summary(ttft_s: Optional[List[float]] = None,
-                       tbt_hist: Optional[Histogram] = None,
-                       e2e_s: Optional[List[float]] = None) -> SLOSummary:
-    """SLO summary from raw samples where lists already exist (the
-    model-free traffic sims keep latency lists for other consumers)."""
-    def pct(xs, q):
-        return float(np.percentile(xs, q)) if xs else 0.0
-
-    out = SLOSummary(n_requests=len(ttft_s or []))
-    if ttft_s:
-        out.ttft_p50_s = pct(ttft_s, 50)
-        out.ttft_p90_s = pct(ttft_s, 90)
-        out.ttft_p99_s = pct(ttft_s, 99)
-    if tbt_hist is not None and tbt_hist.count:
-        out.tbt_p50_s = _q(tbt_hist, 0.5)
-        out.tbt_p90_s = _q(tbt_hist, 0.9)
-        out.tbt_p99_s = _q(tbt_hist, 0.99)
-    if e2e_s:
-        out.e2e_p50_s = pct(e2e_s, 50)
-        out.e2e_p90_s = pct(e2e_s, 90)
-        out.e2e_p99_s = pct(e2e_s, 99)
-    return out
 
 
 def attach_energy_percentiles(summary: SLOSummary, request_j,

@@ -19,6 +19,16 @@ aggregate counters like compile counts). Design constraints, in order:
     timeline as the Stage-I `OccupancyTrace` (what makes the Perfetto
     export a *single* coherent view).
 
+Two clocks, two jobs. ``Telemetry.span`` / ``add_span`` and the SLO
+histograms built on them run on whatever clock the registry is bound to —
+for the batchers, the logical sim clock of the Stage-I trace, which is what
+``launch/obs export`` draws. :func:`host_span` is the wall-clock side: it
+writes a ``jax.profiler.TraceAnnotation`` onto the host line of the
+profiler trace, the same clock as the device's operations, so a device-idle
+gap can be named by the host code that ran in it, and it adds its elapsed
+``time.perf_counter`` seconds to a stats field. It is always on: outside a
+profiler session a span costs one to two microseconds.
+
 Histogram quantiles are estimated from fixed log-spaced buckets: the
 estimate for any order statistic lies inside the bucket that truly
 contains it (bucket counts are exact), clamped to the observed min/max —
@@ -169,7 +179,8 @@ class Histogram:
                 hi = self.edges[b] if b < len(self.edges) else self.max_value
                 lo = max(lo, self.min_value)
                 hi = min(hi, self.max_value)
-                return lo + (hi - lo) * ((target - cum) / c)
+                # lo + (hi - lo) can round one ulp past hi: keep it inside
+                return min(lo + (hi - lo) * ((target - cum) / c), hi)
             cum += c
         return self.max_value
 
@@ -181,7 +192,9 @@ class Histogram:
         f = k - math.floor(k)
         if f == 0.0:
             return lo
-        return lo * (1.0 - f) + self._order_stat(int(math.ceil(k))) * f
+        hi = self._order_stat(int(math.ceil(k)))
+        # the blend can round one ulp past either estimate: keep it between
+        return min(max(lo * (1.0 - f) + hi * f, lo), hi)
 
     # ---------------------------------------------------------------- merge
     def merge(self, other: "Histogram") -> None:
@@ -392,6 +405,39 @@ class Telemetry:
             for n, (k, tot) in sorted(by_name.items()):
                 lines.append(f"  {n:<34} n={k:<7} total={tot:9.3g}s")
         return "\n".join(lines)
+
+
+def host_span(name: str, stats=None, field: Optional[str] = None, **meta):
+    """``with host_span("serve.prefill", stats, "prefill_wall_s", rid=7):``
+    — a wall-clock span on the profiler's clock.
+
+    Opens ``jax.profiler.TraceAnnotation(name, **meta)``, so the span lands
+    on the calling thread's host line of a profiler trace (the name stays
+    bare; `meta` rides as the event's stats), and, when `stats` is given,
+    adds the elapsed ``time.perf_counter()`` seconds to ``stats.<field>``.
+    """
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name, **meta)
+    return ann if stats is None else _TimedSpan(ann, stats, field)
+
+
+class _TimedSpan:
+    __slots__ = ("_ann", "_stats", "_field", "_t0")
+
+    def __init__(self, ann, stats, field: str):
+        self._ann = ann
+        self._stats = stats
+        self._field = field
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        setattr(self._stats, self._field, getattr(self._stats, self._field)
+                + time.perf_counter() - self._t0)
+        return self._ann.__exit__(*exc)
 
 
 _DEFAULT: Optional[Telemetry] = None

@@ -35,7 +35,8 @@ from repro.models.transformer import (init_paged_cache, prefix_tail_rows,
                                       self_spec_draft, write_prefill_to_pages)
 from repro.obs.slo import (RequestTimeline, SLOSummary, SLOTracker,
                            attach_energy_percentiles)
-from repro.obs.telemetry import default_registry, noop_registry
+from repro.obs.telemetry import (default_registry, host_span,
+                                 noop_registry)
 from repro.serve.scheduler import AdmissionQueue, Request, SchedulerStats
 from repro.sim.trace import AccessStats, OccupancyTrace, TraceBundle
 
@@ -374,6 +375,16 @@ class PagedStats(SchedulerStats):
     drafted_tokens: int = 0
     accepted_tokens: int = 0
     rolled_back_pages: int = 0
+    # wall-clock counters, taken at the boundaries of the host_span of the
+    # same name (serve.run, serve.prefill, serve.chunk.wait) on
+    # time.perf_counter, the clock a profiler trace's host lines use
+    run_wall_s: float = 0.0          # inside run()
+    device_wait_s: float = 0.0       # of which blocked on device results
+    queue_wait_s: float = 0.0        # sum of admission start - submit
+    prefill_wall_s: float = 0.0      # serve.prefill spans
+    prefill_tokens: int = 0          # prompt tokens those spans prefilled
+    first_token_hold_s: float = 0.0  # sum of run() return - first token
+    page_waits: int = 0              # admission passes stopped on pages
 
 
 class PagedContinuousBatcher:
@@ -514,6 +525,8 @@ class PagedContinuousBatcher:
         # request spans and the KV counter track on one timeline. bind_clock
         # raises if another engine already owns the registry's clock (a
         # second batcher would silently corrupt the first one's timelines).
+        # The serve.* host_spans and the wall counters of PagedStats are the
+        # other clock: time.perf_counter, on the profiler's host line.
         self.tel = telemetry if telemetry is not None else noop_registry()
         if telemetry is not None:
             telemetry.bind_clock(lambda: self._sim_t, owner=self)
@@ -575,6 +588,8 @@ class PagedContinuousBatcher:
         self._next_tok = np.zeros(num_slots, np.int32)
         self._table = np.zeros((num_slots, self.max_pages_per_slot), np.int32)
         self._sim_t = 0.0
+        # first-token wall stamps of this run(), held until it returns
+        self._first_tokens: List[float] = []
 
         self._cache = init_paged_cache(
             self.cfg, num_slots, num_pages, page_size,
@@ -701,13 +716,18 @@ class PagedContinuousBatcher:
 
     def run(self, max_chunks: int = 10_000) -> List[Request]:
         done: List[Request] = []
-        for _ in range(max_chunks):
-            if not self.queue and all(s is None for s in self.slots):
-                break
-            self._admit(done)
-            self._decode_chunk(done)
-        if self._slo is not None:
-            self.slo_summary()           # refresh stats percentiles once
+        with host_span("serve.run", self.stats, "run_wall_s"):
+            for _ in range(max_chunks):
+                if not self.queue and all(s is None for s in self.slots):
+                    break
+                with host_span("serve.admit"):
+                    self._admit(done)
+                self._decode_chunk(done)
+            if self._slo is not None:
+                self.slo_summary()       # refresh stats percentiles once
+        t = time.perf_counter()
+        self.stats.first_token_hold_s += sum(t - f for f in self._first_tokens)
+        self._first_tokens.clear()
         return done
 
     def slo_summary(self) -> SLOSummary:
@@ -765,6 +785,20 @@ class PagedContinuousBatcher:
     @property
     def _lanes(self) -> int:
         return 2 if self.speculate_k is not None else 1
+
+    def _dequeue(self) -> Request:
+        """Pop the queue head: its admission starts now."""
+        req = self.queue.pop()
+        self.stats.queue_wait_s += time.perf_counter() - req.submitted_wall_s
+        return req
+
+    def _first_token(self, logits) -> int:
+        """The prefill's greedy token, read back to the host (the one sync
+        of an admission, counted as device wait)."""
+        t = time.perf_counter()
+        tok = int(jnp.argmax(logits[0, -1]))
+        self.stats.device_wait_s += time.perf_counter() - t
+        return tok
 
     def _set_page_gauges(self) -> None:
         """Page-count plus bytes-based occupancy gauges: physical = pool
@@ -888,8 +922,9 @@ class PagedContinuousBatcher:
             if worst > self._available_pages() \
                     and not self._preempt_for(req.priority, worst):
                 self._c_wait.inc()
+                self.stats.page_waits += 1
                 break                      # wait for pages to free up
-            self.queue.pop()
+            self._dequeue()
             if (self.prefill_chunk_tokens is not None
                     and prompt_len > self.prefill_chunk_tokens):
                 self._admit_chunked(i, req, done, worst)
@@ -897,25 +932,28 @@ class PagedContinuousBatcher:
             npg = pages_for(prompt_len, self.page_size)
             t_pre = self._sim_t
 
-            batch = {"tokens": jnp.asarray(np.asarray(req.tokens)[None, :],
-                                           jnp.int32)}
-            logits, dense = self._prefill(self.params, batch,
-                                          npg * self.page_size)
-            tok = int(jnp.argmax(logits[0, -1]))
-            self._sim_t += prompt_len * self.prefill_tok_s
-            if self.meter is not None:
-                self.ledger.set_slot_meta(i, req.rid, req.tenant)
-            pages = self.ledger.admit(i, npg, self._sim_t)
-            self._reserved[i] = worst - npg
-            self.stats.pages_allocated += npg
-            self.stats.peak_pages = max(self.stats.peak_pages,
-                                        self.ledger.allocator.n_allocated)
-            self.stats.admitted_kv_bytes += npg * self.page_bytes
-            self.access.add_write("kv", prompt_len * self.row_bytes)
-            self._c_alloc.inc(npg)
+            with host_span("serve.prefill", self.stats, "prefill_wall_s",
+                           rid=req.rid, tokens=prompt_len):
+                batch = {"tokens": jnp.asarray(
+                    np.asarray(req.tokens)[None, :], jnp.int32)}
+                logits, dense = self._prefill(self.params, batch,
+                                              npg * self.page_size)
+                tok = self._first_token(logits)
+                self._sim_t += prompt_len * self.prefill_tok_s
+                if self.meter is not None:
+                    self.ledger.set_slot_meta(i, req.rid, req.tenant)
+                pages = self.ledger.admit(i, npg, self._sim_t)
+                self._reserved[i] = worst - npg
+                self.stats.pages_allocated += npg
+                self.stats.peak_pages = max(self.stats.peak_pages,
+                                            self.ledger.allocator.n_allocated)
+                self.stats.admitted_kv_bytes += npg * self.page_bytes
+                self.access.add_write("kv", prompt_len * self.row_bytes)
+                self._c_alloc.inc(npg)
 
-            self._cache = self._write(self._cache, dense, i,
-                                      jnp.asarray(pages, jnp.int32))
+                self._cache = self._write(self._cache, dense, i,
+                                          jnp.asarray(pages, jnp.int32))
+            self.stats.prefill_tokens += prompt_len
             self._commit_admission(i, req, done, tok, logits, prompt_len,
                                    pages, t_pre)
 
@@ -942,55 +980,61 @@ class PagedContinuousBatcher:
         C = self.prefill_chunk_tokens
         t_pre = self._sim_t
         pos = 0
-        logits = None
         while pos < S:
             take = min(C, S - pos)
-            sl = jnp.asarray(prompt[None, pos:pos + take], jnp.int32)
-            t0 = self._sim_t
-            if pos == 0:
-                new_n = pages_for(take, ps)
-                logits, dense = self._prefill(self.params, {"tokens": sl},
-                                              new_n * ps)
-                self._sim_t += take * self.prefill_tok_s
-                if self.meter is not None:
-                    self.ledger.set_slot_meta(i, req.rid, req.tenant)
-                pages = self.ledger.admit(i, new_n, self._sim_t)
-                self._reserved[i] = worst - new_n
-                self._cache = self._write(self._cache, dense, i,
-                                          jnp.asarray(pages, jnp.int32))
-            else:
-                held = list(self.ledger.slot_pages[i])
-                prefix = self._gather(self._cache,
-                                      jnp.asarray(held, jnp.int32), pos)
-                if self.kv_quantized:
-                    self._c_dequant.inc(len(held))
-                head = prefix_tail_rows(prefix, 0)   # pos is page-aligned
-                logits, suffix = self._prefill_shared(self.params, sl, prefix)
-                self._sim_t += take * self.prefill_tok_s
-                fresh = self.ledger.grow(i, pages_for(pos + take, ps),
-                                         self._sim_t, cause="admission")
-                self._reserved[i] -= len(fresh)
-                new_n = len(fresh)
-                self._cache = self._write_shared(
-                    self._cache, suffix, head, jnp.int32(i),
-                    jnp.asarray(held, jnp.int32),
-                    jnp.asarray(fresh, jnp.int32))
-            self.stats.pages_allocated += new_n
-            self.stats.admitted_kv_bytes += new_n * self.page_bytes
-            self.stats.peak_pages = max(self.stats.peak_pages,
-                                        self.ledger.allocator.n_allocated)
-            self.stats.prefill_slices += 1
-            self.access.add_write("kv", take * self.row_bytes)
-            self._c_alloc.inc(new_n)
-            self._c_slices.inc()
-            if self.tel.enabled:
-                self.tel.add_span("prefill_slice", t0, self._sim_t, slot=i,
-                                  rid=req.rid, tokens=take)
-            pos += take
+            # one serve.prefill span per slice: the decode chunks between
+            # slices are the other slots' time, not this prefill's
+            with host_span("serve.prefill", self.stats, "prefill_wall_s",
+                           rid=req.rid, tokens=take):
+                sl = jnp.asarray(prompt[None, pos:pos + take], jnp.int32)
+                t0 = self._sim_t
+                if pos == 0:
+                    new_n = pages_for(take, ps)
+                    logits, dense = self._prefill(
+                        self.params, {"tokens": sl}, new_n * ps)
+                    self._sim_t += take * self.prefill_tok_s
+                    if self.meter is not None:
+                        self.ledger.set_slot_meta(i, req.rid, req.tenant)
+                    pages = self.ledger.admit(i, new_n, self._sim_t)
+                    self._reserved[i] = worst - new_n
+                    self._cache = self._write(self._cache, dense, i,
+                                              jnp.asarray(pages, jnp.int32))
+                else:
+                    held = list(self.ledger.slot_pages[i])
+                    prefix = self._gather(self._cache,
+                                          jnp.asarray(held, jnp.int32), pos)
+                    if self.kv_quantized:
+                        self._c_dequant.inc(len(held))
+                    head = prefix_tail_rows(prefix, 0)  # pos is page-aligned
+                    logits, suffix = self._prefill_shared(self.params, sl,
+                                                          prefix)
+                    self._sim_t += take * self.prefill_tok_s
+                    fresh = self.ledger.grow(i, pages_for(pos + take, ps),
+                                             self._sim_t, cause="admission")
+                    self._reserved[i] -= len(fresh)
+                    new_n = len(fresh)
+                    self._cache = self._write_shared(
+                        self._cache, suffix, head, jnp.int32(i),
+                        jnp.asarray(held, jnp.int32),
+                        jnp.asarray(fresh, jnp.int32))
+                self.stats.pages_allocated += new_n
+                self.stats.admitted_kv_bytes += new_n * self.page_bytes
+                self.stats.peak_pages = max(self.stats.peak_pages,
+                                            self.ledger.allocator.n_allocated)
+                self.stats.prefill_slices += 1
+                self.access.add_write("kv", take * self.row_bytes)
+                self._c_alloc.inc(new_n)
+                self._c_slices.inc()
+                if self.tel.enabled:
+                    self.tel.add_span("prefill_slice", t0, self._sim_t,
+                                      slot=i, rid=req.rid, tokens=take)
+                pos += take
+                if pos == S:
+                    tok = self._first_token(logits)
+            self.stats.prefill_tokens += take
             if pos < S:
                 # let the active slots stream tokens before the next slice
                 self._decode_chunk(done)
-        tok = int(jnp.argmax(logits[0, -1]))
         self._commit_admission(i, req, done, tok, logits, S,
                                self.ledger.slot_pages[i], t_pre)
 
@@ -1018,6 +1062,8 @@ class PagedContinuousBatcher:
         self._table[i, :] = 0
         self._table[i, :len(table_pages)] = table_pages
         req.output.append(tok)
+        req.first_token_wall_s = time.perf_counter()
+        self._first_tokens.append(req.first_token_wall_s)
         if self.collect_logits:
             req.logits.append(np.asarray(logits[0, -1]))
         self.stats.admitted += 1
@@ -1092,12 +1138,13 @@ class PagedContinuousBatcher:
                 v = self._preempt_victim(req.priority)
                 if v is None:
                     self._c_wait.inc()
+                    self.stats.page_waits += 1
                     return False
                 self._preempt(v, self._sim_t)
             # eviction/preemption may have changed the matched path: re-probe
             match = self.ledger.index.probe(prompt, limit=S - 1)
             short = demand(match) - self._available_pages()
-        self.queue.pop()
+        self._dequeue()
 
         n_full, j = len(match.pages), match.tail_tokens
         m = n_full * ps + j
@@ -1117,66 +1164,69 @@ class PagedContinuousBatcher:
             slices = [suffix_len]
 
         pos = m
-        logits = None
         for si, take in enumerate(slices):
-            sl = jnp.asarray(prompt[None, pos:pos + take], jnp.int32)
-            t0 = self._sim_t
-            if si == 0:
-                gather_ids = list(match.pages) + \
-                    ([match.tail_page] if j else [])
-                prefix = self._gather(self._cache,
-                                      jnp.asarray(gather_ids, jnp.int32), m)
-                if self.kv_quantized and gather_ids:
-                    self._c_dequant.inc(len(gather_ids))
-                head = prefix_tail_rows(prefix, j)
-                logits, suffix = self._prefill_shared(self.params, sl,
-                                                      prefix)
-                self._sim_t += take * self.prefill_tok_s  # suffix only
-                new_n = pages_for(m + take, ps) - n_full
-                if self.meter is not None:
-                    self.ledger.set_slot_meta(i, req.rid, req.tenant)
-                fresh = self.ledger.admit(i, new_n, self._sim_t,
-                                          shared=match.pages)
-                self._reserved[i] = demand(match) - new_n
-                self._cache = self._write_shared(
-                    self._cache, suffix, head, jnp.int32(i),
-                    jnp.asarray(match.pages, jnp.int32),
-                    jnp.asarray(fresh, jnp.int32))
-            else:
-                held = list(self.ledger.slot_pages[i])
-                prefix = self._gather(self._cache,
-                                      jnp.asarray(held, jnp.int32), pos)
-                if self.kv_quantized:
-                    self._c_dequant.inc(len(held))
-                head = prefix_tail_rows(prefix, 0)   # pos is page-aligned
-                logits, suffix = self._prefill_shared(self.params, sl,
-                                                      prefix)
-                self._sim_t += take * self.prefill_tok_s
-                fresh = self.ledger.grow(i, pages_for(pos + take, ps),
-                                         self._sim_t, cause="admission")
-                self._reserved[i] -= len(fresh)
-                new_n = len(fresh)
-                self._cache = self._write_shared(
-                    self._cache, suffix, head, jnp.int32(i),
-                    jnp.asarray(held, jnp.int32),
-                    jnp.asarray(fresh, jnp.int32))
-            self.stats.pages_allocated += new_n
-            self.stats.peak_pages = max(self.stats.peak_pages,
-                                        self.ledger.allocator.n_allocated)
-            self.stats.admitted_kv_bytes += new_n * self.page_bytes
-            self.access.add_write("kv", take * self.row_bytes)
-            self._c_alloc.inc(new_n)
-            if len(slices) > 1:
-                self.stats.prefill_slices += 1
-                self._c_slices.inc()
-                if self.tel.enabled:
-                    self.tel.add_span("prefill_slice", t0, self._sim_t,
-                                      slot=i, rid=req.rid, tokens=take)
-            pos += take
+            with host_span("serve.prefill", self.stats, "prefill_wall_s",
+                           rid=req.rid, tokens=take):
+                sl = jnp.asarray(prompt[None, pos:pos + take], jnp.int32)
+                t0 = self._sim_t
+                if si == 0:
+                    gather_ids = list(match.pages) + \
+                        ([match.tail_page] if j else [])
+                    prefix = self._gather(
+                        self._cache, jnp.asarray(gather_ids, jnp.int32), m)
+                    if self.kv_quantized and gather_ids:
+                        self._c_dequant.inc(len(gather_ids))
+                    head = prefix_tail_rows(prefix, j)
+                    logits, suffix = self._prefill_shared(self.params, sl,
+                                                          prefix)
+                    self._sim_t += take * self.prefill_tok_s  # suffix only
+                    new_n = pages_for(m + take, ps) - n_full
+                    if self.meter is not None:
+                        self.ledger.set_slot_meta(i, req.rid, req.tenant)
+                    fresh = self.ledger.admit(i, new_n, self._sim_t,
+                                              shared=match.pages)
+                    self._reserved[i] = demand(match) - new_n
+                    self._cache = self._write_shared(
+                        self._cache, suffix, head, jnp.int32(i),
+                        jnp.asarray(match.pages, jnp.int32),
+                        jnp.asarray(fresh, jnp.int32))
+                else:
+                    held = list(self.ledger.slot_pages[i])
+                    prefix = self._gather(self._cache,
+                                          jnp.asarray(held, jnp.int32), pos)
+                    if self.kv_quantized:
+                        self._c_dequant.inc(len(held))
+                    head = prefix_tail_rows(prefix, 0)  # pos is page-aligned
+                    logits, suffix = self._prefill_shared(self.params, sl,
+                                                          prefix)
+                    self._sim_t += take * self.prefill_tok_s
+                    fresh = self.ledger.grow(i, pages_for(pos + take, ps),
+                                             self._sim_t, cause="admission")
+                    self._reserved[i] -= len(fresh)
+                    new_n = len(fresh)
+                    self._cache = self._write_shared(
+                        self._cache, suffix, head, jnp.int32(i),
+                        jnp.asarray(held, jnp.int32),
+                        jnp.asarray(fresh, jnp.int32))
+                self.stats.pages_allocated += new_n
+                self.stats.peak_pages = max(self.stats.peak_pages,
+                                            self.ledger.allocator.n_allocated)
+                self.stats.admitted_kv_bytes += new_n * self.page_bytes
+                self.access.add_write("kv", take * self.row_bytes)
+                self._c_alloc.inc(new_n)
+                if len(slices) > 1:
+                    self.stats.prefill_slices += 1
+                    self._c_slices.inc()
+                    if self.tel.enabled:
+                        self.tel.add_span("prefill_slice", t0, self._sim_t,
+                                          slot=i, rid=req.rid, tokens=take)
+                pos += take
+                if pos == S:
+                    tok = self._first_token(logits)
+            self.stats.prefill_tokens += take
             if pos < S:
                 # let the active slots stream tokens before the next slice
                 self._decode_chunk(done)
-        tok = int(jnp.argmax(logits[0, -1]))
         if m:
             self.stats.prefix_hits += 1
             self.stats.prefix_tokens_reused += m
@@ -1223,80 +1273,87 @@ class PagedContinuousBatcher:
         live = [i for i, s in enumerate(self.slots) if s is not None]
         if not live:
             return
-        t0 = self._sim_t
-        # grow page tables to cover this chunk's worst case (reservation at
-        # admission guarantees these allocations succeed)
-        remaining = np.zeros(self.num_slots, np.int32)
-        for i in live:
-            req = self.slots[i]
-            remaining[i] = req.max_new_tokens - len(req.output)
-            steps_i = min(self.chunk_steps, int(remaining[i]))
-            new_pages = self.ledger.grow(
-                i, pages_for(int(self._ctx[i]) + steps_i, self.page_size), t0)
-            if new_pages:
-                npg_have = len(self.ledger.slot_pages[i])
-                self._table[i, npg_have - len(new_pages):npg_have] = new_pages
-                self._reserved[i] -= len(new_pages)
-                self.stats.pages_allocated += len(new_pages)
-                self.stats.admitted_kv_bytes += len(new_pages) * self.page_bytes
-                self._c_alloc.inc(len(new_pages))
-            if self.prefix_cache:
-                self._cow_for_chunk(i, steps_i, t0)
-        self.stats.peak_pages = max(self.stats.peak_pages,
-                                    self.ledger.allocator.n_allocated)
+        with host_span("serve.chunk.prep"):
+            t0 = self._sim_t
+            # grow page tables to cover this chunk's worst case (reservation
+            # at admission guarantees these allocations succeed)
+            remaining = np.zeros(self.num_slots, np.int32)
+            for i in live:
+                req = self.slots[i]
+                remaining[i] = req.max_new_tokens - len(req.output)
+                steps_i = min(self.chunk_steps, int(remaining[i]))
+                new_pages = self.ledger.grow(
+                    i, pages_for(int(self._ctx[i]) + steps_i, self.page_size),
+                    t0)
+                if new_pages:
+                    npg_have = len(self.ledger.slot_pages[i])
+                    self._table[i, npg_have - len(new_pages):npg_have] = \
+                        new_pages
+                    self._reserved[i] -= len(new_pages)
+                    self.stats.pages_allocated += len(new_pages)
+                    self.stats.admitted_kv_bytes += \
+                        len(new_pages) * self.page_bytes
+                    self._c_alloc.inc(len(new_pages))
+                if self.prefix_cache:
+                    self._cow_for_chunk(i, steps_i, t0)
+            self.stats.peak_pages = max(self.stats.peak_pages,
+                                        self.ledger.allocator.n_allocated)
 
-        cache = self._cache
-        # host is the source of truth between chunks: push the page-table
-        # mirror and the liveness mask (covers slots retired host-side at
-        # admission, whose device `active` flag was never flipped in-scan)
-        cache["page_table"] = jnp.asarray(self._table)
-        cache["active"] = jnp.asarray(
-            [s is not None for s in self.slots])
-        emitted, cache, tok, _ = self._loop(
-            self.params, cache, jnp.asarray(self._next_tok[:, None]),
-            jnp.asarray([(self.slots[i].eos_id if self.slots[i] is not None
-                          and self.slots[i].eos_id is not None else -1)
-                         for i in range(self.num_slots)], jnp.int32),
-            jnp.asarray(remaining))
+            cache = self._cache
+            # host is the source of truth between chunks: push the page-table
+            # mirror and the liveness mask (covers slots retired host-side at
+            # admission, whose device `active` flag was never flipped in-scan)
+            cache["page_table"] = jnp.asarray(self._table)
+            cache["active"] = jnp.asarray(
+                [s is not None for s in self.slots])
+            emitted, cache, tok, _ = self._loop(
+                self.params, cache, jnp.asarray(self._next_tok[:, None]),
+                jnp.asarray([(self.slots[i].eos_id
+                              if self.slots[i] is not None
+                              and self.slots[i].eos_id is not None else -1)
+                             for i in range(self.num_slots)], jnp.int32),
+                jnp.asarray(remaining))
         self._cache = cache
         self.stats.chunks += 1
-        step_logits = None
-        if self.collect_logits:
-            emitted, step_logits = emitted
-            step_logits = np.asarray(step_logits)  # (steps, num_slots, V)
-        emitted = np.asarray(emitted)                    # (steps, num_slots)
-        self._next_tok = np.array(tok[:, 0])
-        still_active = np.array(cache["active"])
+        with host_span("serve.chunk.wait", self.stats, "device_wait_s"):
+            step_logits = None
+            if self.collect_logits:
+                emitted, step_logits = emitted
+                step_logits = np.asarray(step_logits)  # (steps, slots, V)
+            emitted = np.asarray(emitted)                # (steps, num_slots)
+            self._next_tok = np.array(tok[:, 0])
+            still_active = np.array(cache["active"])
         self._sim_t = t0 + self.chunk_steps * self.step_time_s
         self._c_chunks.inc()
         self.tel.add_span("decode_chunk", t0, self._sim_t, slots=len(live))
 
-        for i in live:
-            req = self.slots[i]
-            col = emitted[:, i]
-            neg = np.nonzero(col < 0)[0]
-            g = int(neg[0]) if len(neg) else len(col)
-            req.output.extend(int(t) for t in col[:g])
-            if step_logits is not None:
-                req.logits.extend(step_logits[:g, i])
-            self.stats.decode_steps += g
-            # page-granular access accounting: each step streams the resident
-            # pages and appends one row
-            ctxs = int(self._ctx[i]) + 1 + np.arange(g)
-            pages_read = int((np.ceil(ctxs / self.page_size)).sum())
-            self.access.add_read("kv", pages_read * self.page_bytes)
-            self.access.add_write("kv", g * self.row_bytes)
-            if self.kv_quantized and pages_read:
-                # every page the fused kernel streams is dequantized
-                # in-register
-                self._c_dequant.inc(pages_read)
-            self._c_steps.inc(g)
-            if req.timeline is not None and g:
-                req.timeline.token_ts.extend(
-                    (t0 + self.step_time_s * np.arange(1, g + 1)).tolist())
-            self._ctx[i] += g
-            if not still_active[i]:
-                self._retire(i, req, done, t0 + g * self.step_time_s)
+        with host_span("serve.chunk.emit"):
+            for i in live:
+                req = self.slots[i]
+                col = emitted[:, i]
+                neg = np.nonzero(col < 0)[0]
+                g = int(neg[0]) if len(neg) else len(col)
+                req.output.extend(int(t) for t in col[:g])
+                if step_logits is not None:
+                    req.logits.extend(step_logits[:g, i])
+                self.stats.decode_steps += g
+                # page-granular access accounting: each step streams the
+                # resident pages and appends one row
+                ctxs = int(self._ctx[i]) + 1 + np.arange(g)
+                pages_read = int((np.ceil(ctxs / self.page_size)).sum())
+                self.access.add_read("kv", pages_read * self.page_bytes)
+                self.access.add_write("kv", g * self.row_bytes)
+                if self.kv_quantized and pages_read:
+                    # every page the fused kernel streams is dequantized
+                    # in-register
+                    self._c_dequant.inc(pages_read)
+                self._c_steps.inc(g)
+                if req.timeline is not None and g:
+                    req.timeline.token_ts.extend(
+                        (t0 + self.step_time_s * np.arange(1, g + 1)).tolist())
+                self._ctx[i] += g
+                if not still_active[i]:
+                    self._retire(i, req, done, t0 + g * self.step_time_s)
 
     def _spec_chunk(self, done: List[Request]) -> None:
         """One speculative decode chunk: `spec_rounds_per_chunk` draft-then-
@@ -1309,130 +1366,134 @@ class PagedContinuousBatcher:
         live = [i for i, s in enumerate(self.slots) if s is not None]
         if not live:
             return
-        t0 = self._sim_t
-        V = self.speculate_k + 1
-        R = self.spec_rounds_per_chunk
-        ps = self.page_size
-        remaining = np.zeros(self.num_slots, np.int32)
-        for i in live:
-            req = self.slots[i]
-            remaining[i] = req.max_new_tokens - len(req.output)
-            # worst rows this chunk can touch: every round writes V rows at
-            # pos..pos+V-1 and advances >= 1, and the last active round
-            # starts with >= 1 token remaining
-            rows = int(self._ctx[i]) + min(R * V, int(remaining[i]) + V - 1)
-            npg = pages_for(rows, ps)
-            new_pages = self.ledger.grow(i, npg, t0)
-            if new_pages:
-                have = len(self.ledger.slot_pages[i])
-                self._table[i, have - len(new_pages):have] = new_pages
-                self._reserved[i] -= len(new_pages)
-                self.stats.pages_allocated += len(new_pages)
-                self.stats.admitted_kv_bytes += \
-                    len(new_pages) * self.page_bytes
-                self._c_alloc.inc(len(new_pages))
-            dnew = self.ledger.grow_draft(i, npg, t0)
-            if dnew:
-                dhave = len(self.ledger.draft_pages[i])
-                self._draft_table[i, dhave - len(dnew):dhave] = dnew
-                self._reserved[i] -= len(dnew)
-                self.stats.pages_allocated += len(dnew)
-                self.stats.admitted_kv_bytes += \
-                    len(dnew) * self.draft_page_bytes
-                self._c_alloc.inc(len(dnew))
-            if self.prefix_cache:
-                # the verify window writes past ctx: COW every shared page
-                # in the full speculative write range, not just chunk_steps
-                self._cow_for_chunk(i, rows - int(self._ctx[i]), t0)
-        self.stats.peak_pages = max(self.stats.peak_pages,
-                                    self.ledger.allocator.n_allocated)
+        with host_span("serve.chunk.prep"):
+            t0 = self._sim_t
+            V = self.speculate_k + 1
+            R = self.spec_rounds_per_chunk
+            ps = self.page_size
+            remaining = np.zeros(self.num_slots, np.int32)
+            for i in live:
+                req = self.slots[i]
+                remaining[i] = req.max_new_tokens - len(req.output)
+                # worst rows this chunk can touch: every round writes V rows at
+                # pos..pos+V-1 and advances >= 1, and the last active round
+                # starts with >= 1 token remaining
+                rows = int(self._ctx[i]) + min(R * V,
+                                               int(remaining[i]) + V - 1)
+                npg = pages_for(rows, ps)
+                new_pages = self.ledger.grow(i, npg, t0)
+                if new_pages:
+                    have = len(self.ledger.slot_pages[i])
+                    self._table[i, have - len(new_pages):have] = new_pages
+                    self._reserved[i] -= len(new_pages)
+                    self.stats.pages_allocated += len(new_pages)
+                    self.stats.admitted_kv_bytes += \
+                        len(new_pages) * self.page_bytes
+                    self._c_alloc.inc(len(new_pages))
+                dnew = self.ledger.grow_draft(i, npg, t0)
+                if dnew:
+                    dhave = len(self.ledger.draft_pages[i])
+                    self._draft_table[i, dhave - len(dnew):dhave] = dnew
+                    self._reserved[i] -= len(dnew)
+                    self.stats.pages_allocated += len(dnew)
+                    self.stats.admitted_kv_bytes += \
+                        len(dnew) * self.draft_page_bytes
+                    self._c_alloc.inc(len(dnew))
+                if self.prefix_cache:
+                    # the verify window writes past ctx: COW every shared page
+                    # in the full speculative write range, not just chunk_steps
+                    self._cow_for_chunk(i, rows - int(self._ctx[i]), t0)
+            self.stats.peak_pages = max(self.stats.peak_pages,
+                                        self.ledger.allocator.n_allocated)
 
-        cache = self._cache
-        dcache = self._draft_cache
-        cache["page_table"] = jnp.asarray(self._table)
-        dcache["page_table"] = jnp.asarray(self._draft_table)
-        # two separate transfers: both caches are donated, so the liveness
-        # masks must be distinct buffers even though their contents match
-        act_np = np.array([s is not None for s in self.slots])
-        cache["active"] = jnp.asarray(act_np)
-        dcache["active"] = jnp.asarray(act_np.copy())
-        emitted, cache, dcache, tok, _ = self._spec_loop(
-            self.params, self.draft_params, cache, dcache,
-            jnp.asarray(self._next_tok[:, None]),
-            jnp.asarray([(self.slots[i].eos_id if self.slots[i] is not None
-                          and self.slots[i].eos_id is not None else -1)
-                         for i in range(self.num_slots)], jnp.int32),
-            jnp.asarray(remaining))
+            cache = self._cache
+            dcache = self._draft_cache
+            cache["page_table"] = jnp.asarray(self._table)
+            dcache["page_table"] = jnp.asarray(self._draft_table)
+            # two separate transfers: both caches are donated, so the liveness
+            # masks must be distinct buffers even though their contents match
+            act_np = np.array([s is not None for s in self.slots])
+            cache["active"] = jnp.asarray(act_np)
+            dcache["active"] = jnp.asarray(act_np.copy())
+            emitted, cache, dcache, tok, _ = self._spec_loop(
+                self.params, self.draft_params, cache, dcache,
+                jnp.asarray(self._next_tok[:, None]),
+                jnp.asarray([(self.slots[i].eos_id if self.slots[i] is not None
+                              and self.slots[i].eos_id is not None else -1)
+                             for i in range(self.num_slots)], jnp.int32),
+                jnp.asarray(remaining))
         self._cache = cache
         self._draft_cache = dcache
         self.stats.chunks += 1
-        emitted = np.asarray(emitted)            # (rounds, num_slots, V)
-        self._next_tok = np.array(tok[:, 0])
-        still_active = np.array(cache["active"])
+        with host_span("serve.chunk.wait", self.stats, "device_wait_s"):
+            emitted = np.asarray(emitted)        # (rounds, num_slots, V)
+            self._next_tok = np.array(tok[:, 0])
+            still_active = np.array(cache["active"])
         self._sim_t = t0 + R * self.spec_round_time_s
         self._c_chunks.inc()
         self.tel.add_span("decode_chunk", t0, self._sim_t, slots=len(live))
 
-        for i in live:
-            req = self.slots[i]
-            block = emitted[:, i, :]             # (rounds, V), -1 padded
-            m_r = (block >= 0).sum(axis=1)       # per-round accepted count
-            rounds_used = int((m_r > 0).sum())
-            toks = block.ravel()
-            toks = toks[toks >= 0]
-            g = int(len(toks))
-            req.output.extend(int(t) for t in toks)
-            self.stats.decode_steps += g
-            self.stats.spec_rounds += rounds_used
-            self.stats.drafted_tokens += rounds_used * self.speculate_k
-            self.stats.accepted_tokens += g
-            self._c_spec_rounds.inc(rounds_used)
-            self._c_drafted.inc(rounds_used * self.speculate_k)
-            self._c_accepted.inc(g)
-            # page-granular access accounting, per round: the verify kernel
-            # streams the target's resident pages once; the draft streams
-            # its own lane for each of its k+1 sequential steps
-            ctx = int(self._ctx[i])
-            pos = ctx
-            pages_t = 0
-            pages_d = 0
-            for r in range(rounds_used):
-                per_round = -(-(pos + V) // ps)
-                pages_t += per_round
-                pages_d += (self.speculate_k + 1) * per_round
-                pos += int(m_r[r])
-            self.access.add_read("kv", pages_t * self.page_bytes
-                                 + pages_d * self.draft_page_bytes)
-            self.access.add_write(
-                "kv", rounds_used * (V * self.row_bytes
-                                     + (self.speculate_k + 1)
-                                     * self.draft_row_bytes))
-            if self.kv_quantized and pages_t:
-                self._c_dequant.inc(pages_t + pages_d)
-            self._c_steps.inc(g)
-            if req.timeline is not None and g:
-                ts: List[float] = []
+        with host_span("serve.chunk.emit"):
+            for i in live:
+                req = self.slots[i]
+                block = emitted[:, i, :]             # (rounds, V), -1 padded
+                m_r = (block >= 0).sum(axis=1)       # per-round accepted count
+                rounds_used = int((m_r > 0).sum())
+                toks = block.ravel()
+                toks = toks[toks >= 0]
+                g = int(len(toks))
+                req.output.extend(int(t) for t in toks)
+                self.stats.decode_steps += g
+                self.stats.spec_rounds += rounds_used
+                self.stats.drafted_tokens += rounds_used * self.speculate_k
+                self.stats.accepted_tokens += g
+                self._c_spec_rounds.inc(rounds_used)
+                self._c_drafted.inc(rounds_used * self.speculate_k)
+                self._c_accepted.inc(g)
+                # page-granular access accounting, per round: the verify kernel
+                # streams the target's resident pages once; the draft streams
+                # its own lane for each of its k+1 sequential steps
+                ctx = int(self._ctx[i])
+                pos = ctx
+                pages_t = 0
+                pages_d = 0
                 for r in range(rounds_used):
-                    ts.extend([t0 + (r + 1) * self.spec_round_time_s]
-                              * int(m_r[r]))
-                req.timeline.token_ts.extend(ts)
-            self._ctx[i] = ctx + g
-            t_end = t0 + rounds_used * self.spec_round_time_s
-            # rollback-by-page-truncation: both lanes drop every page past
-            # the accepted context; freed pages rejoin the reservation
-            ft, fd = self.ledger.truncate_rows(i, int(self._ctx[i]), t_end)
-            nf = len(ft) + len(fd)
-            if nf:
-                keep = pages_for(int(self._ctx[i]), ps)
-                self._table[i, keep:] = 0
-                self._draft_table[i, keep:] = 0
-                self._reserved[i] += nf
-                self.stats.pages_freed += nf
-                self.stats.rolled_back_pages += nf
-                self._c_freed.inc(nf)
-                self._c_rollback.inc(nf)
-            if not still_active[i]:
-                self._retire(i, req, done, t_end)
+                    per_round = -(-(pos + V) // ps)
+                    pages_t += per_round
+                    pages_d += (self.speculate_k + 1) * per_round
+                    pos += int(m_r[r])
+                self.access.add_read("kv", pages_t * self.page_bytes
+                                     + pages_d * self.draft_page_bytes)
+                self.access.add_write(
+                    "kv", rounds_used * (V * self.row_bytes
+                                         + (self.speculate_k + 1)
+                                         * self.draft_row_bytes))
+                if self.kv_quantized and pages_t:
+                    self._c_dequant.inc(pages_t + pages_d)
+                self._c_steps.inc(g)
+                if req.timeline is not None and g:
+                    ts: List[float] = []
+                    for r in range(rounds_used):
+                        ts.extend([t0 + (r + 1) * self.spec_round_time_s]
+                                  * int(m_r[r]))
+                    req.timeline.token_ts.extend(ts)
+                self._ctx[i] = ctx + g
+                t_end = t0 + rounds_used * self.spec_round_time_s
+                # rollback-by-page-truncation: both lanes drop every page past
+                # the accepted context; freed pages rejoin the reservation
+                ft, fd = self.ledger.truncate_rows(i, int(self._ctx[i]), t_end)
+                nf = len(ft) + len(fd)
+                if nf:
+                    keep = pages_for(int(self._ctx[i]), ps)
+                    self._table[i, keep:] = 0
+                    self._draft_table[i, keep:] = 0
+                    self._reserved[i] += nf
+                    self.stats.pages_freed += nf
+                    self.stats.rolled_back_pages += nf
+                    self._c_freed.inc(nf)
+                    self._c_rollback.inc(nf)
+                if not still_active[i]:
+                    self._retire(i, req, done, t_end)
 
 
 def loop_compile_count() -> int:

@@ -102,10 +102,13 @@ class Request:
     # engine's *logical sim clock* — the time base of the occupancy trace
     # and the SLO percentiles, so `latency_s` agrees with the reported e2e
     # distribution; `*_wall_s` are time.perf_counter stamps for host-side
-    # profiling (jit/compile/dispatch overhead included)
+    # profiling (jit/compile/dispatch overhead included);
+    # `first_token_wall_s` is when the first token reached the host (the
+    # paged batcher stamps it; the engine hands it back at run()'s return)
     submitted_s: float = 0.0
     finished_s: float = 0.0
     submitted_wall_s: float = 0.0
+    first_token_wall_s: float = 0.0
     finished_wall_s: float = 0.0
     # times this request was preempted-and-requeued (paged batcher only)
     preemptions: int = 0
