@@ -10,11 +10,11 @@ share one HBM->VMEM page copy, which is the point: scoring k + 1 candidates
 costs one pass over the resident pages instead of k + 1 sequential decode
 calls.
 
-Grid (B, K, P) exactly like `paged_gqa_decode`: kv heads parallel, pages
-innermost sequential so the fp32 split-K online-softmax scratch carries
-across them. The only differences are the fatter query block (V * group
-rows instead of group) and the per-row causal bound derived from the row's
-spec index (row // group).
+Grid (B, K, P), the one-page grid of `paged_gqa_decode_quant`: kv heads
+parallel, pages innermost sequential so the fp32 split-K online-softmax
+scratch carries across them. The only differences are the fatter query
+block (V * group rows instead of group) and the per-row causal bound
+derived from the row's spec index (row // group).
 """
 from __future__ import annotations
 

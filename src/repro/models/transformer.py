@@ -599,9 +599,10 @@ def apply_block_decode(cfg, kind: str, p: dict, x: jax.Array, cache: dict,
 
 def apply_block_decode_paged(cfg, kind: str, p: dict, x: jax.Array,
                              cache: dict, pos: jax.Array,
-                             page_table: jax.Array,
+                             lengths: jax.Array, page_table: jax.Array,
                              attn_backend: str = "auto"):
     """Paged decode block. x: (B,1,D); pos: (B,) true per-slot positions;
+    lengths: (B,) rows each slot attends over (pos + 1, 0 where inactive);
     page_table: (B, P). Returns (x_out, new_cache).
 
     Full-attention blocks write the new K/V row through the page table
@@ -635,12 +636,12 @@ def apply_block_decode_paged(cfg, kind: str, p: dict, x: jax.Array,
             ks = ks.at[pidx, :, off].set(sk)
             vs = vs.at[pidx, :, off].set(sv)
             o = paged_gqa_decode_quant(q[:, 0], kp, vp, ks, vs, page_table,
-                                       pos + 1, backend=attn_backend)
+                                       lengths, backend=attn_backend)
             new_entry = {"kp": kp, "vp": vp, "ks": ks, "vs": vs}
         else:
             kp = kp.at[pidx, :, off].set(_pool_cast(k[:, 0], kp.dtype))
             vp = vp.at[pidx, :, off].set(_pool_cast(v[:, 0], vp.dtype))
-            o = paged_gqa_decode(q[:, 0], kp, vp, page_table, pos + 1,
+            o = paged_gqa_decode(q[:, 0], kp, vp, page_table, lengths,
                                  backend=attn_backend)
             new_entry = {"kp": kp, "vp": vp}
         o = o.reshape(B, 1, cfg.q_dim) @ p["attn"]["wo"].astype(x.dtype)
@@ -1040,11 +1041,13 @@ class DecoderLM:
         Per-slot positions are exact: each slot embeds/ropes at its own
         `pos`, writes its K/V row through its page-table row, and attends
         over exactly `pos + 1` tokens. Inactive slots run masked (null page)
-        and their `pos` does not advance."""
+        and attend over no rows, so the paged kernel copies nothing for
+        them; their `pos` does not advance."""
         cfg = self.cfg
         pos = cache["pos"]
         page_table = cache["page_table"]
         active = cache["active"]
+        lengths = jnp.where(active, pos + 1, 0)
         x = embed_tokens(cfg, params["embed"], tokens, pos[:, None],
                          self.compute_dtype)
         pat = cfg.block_pattern
@@ -1056,7 +1059,7 @@ class DecoderLM:
             for i, kind in enumerate(pat):
                 x, nc = apply_block_decode_paged(
                     cfg, kind, slot_params[i], x, slot_caches[i], pos,
-                    page_table, attn_backend)
+                    lengths, page_table, attn_backend)
                 new_caches.append(nc)
             return x, tuple(new_caches)
 
@@ -1070,7 +1073,8 @@ class DecoderLM:
         new_tail = []
         for tp, kind, tc in zip(params["tail"], tail_kinds, cache["tail"]):
             x, nc = apply_block_decode_paged(cfg, kind, tp, x, tc, pos,
-                                             page_table, attn_backend)
+                                             lengths, page_table,
+                                             attn_backend)
             new_tail.append(nc)
         new_cache["tail"] = new_tail
         new_cache["pos"] = pos + active.astype(jnp.int32)

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_gqa_decode import pages_per_block
 from repro.kernels.quant import kv_dtype_spec
 from repro.models.transformer import (init_paged_cache, prefix_tail_rows,
                                       self_spec_draft, write_prefill_to_pages)
@@ -385,6 +386,11 @@ class PagedStats(SchedulerStats):
     prefill_tokens: int = 0          # prompt tokens those spans prefilled
     first_token_hold_s: float = 0.0  # sum of run() return - first token
     page_waits: int = 0              # admission passes stopped on pages
+    # compute blocks of the paged decode kernel (bf16 / fp8 pages), summed
+    # over decode steps: blocks it ran (those holding a live row of an
+    # active slot) and blocks in its grid (slots x table blocks)
+    attn_blocks_live: int = 0
+    attn_blocks_total: int = 0
 
 
 class PagedContinuousBatcher:
@@ -579,6 +585,12 @@ class PagedContinuousBatcher:
         self.ledger.meter = meter
         self.access = AccessStats()
         self.stats = PagedStats()
+        ppb = pages_per_block(self.cfg.num_kv_heads, page_size,
+                              self.cfg.head_dim, self.max_pages_per_slot)
+        self._attn_block_rows = ppb * page_size
+        self._attn_grid = num_slots * -(-self.max_pages_per_slot // ppb)
+        # int8 pools go through the one-page kernel, which has no blocks
+        self._attn_blocks = kv_spec.name != "int8"
 
         self.queue = AdmissionQueue()
         self.slots: List[Optional[Request]] = [None] * num_slots
@@ -1341,6 +1353,9 @@ class PagedContinuousBatcher:
                 # resident pages and appends one row
                 ctxs = int(self._ctx[i]) + 1 + np.arange(g)
                 pages_read = int((np.ceil(ctxs / self.page_size)).sum())
+                if self._attn_blocks:
+                    self.stats.attn_blocks_live += int(
+                        (-(-ctxs // self._attn_block_rows)).sum())
                 self.access.add_read("kv", pages_read * self.page_bytes)
                 self.access.add_write("kv", g * self.row_bytes)
                 if self.kv_quantized and pages_read:
@@ -1354,6 +1369,9 @@ class PagedContinuousBatcher:
                 self._ctx[i] += g
                 if not still_active[i]:
                     self._retire(i, req, done, t0 + g * self.step_time_s)
+            if self._attn_blocks:
+                self.stats.attn_blocks_total += \
+                    self.chunk_steps * self._attn_grid
 
     def _spec_chunk(self, done: List[Request]) -> None:
         """One speculative decode chunk: `spec_rounds_per_chunk` draft-then-

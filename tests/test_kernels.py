@@ -93,16 +93,20 @@ def test_gqa_decode_respects_length_mask():
 
 # --- paged gqa decode ---------------------------------------------------------
 
-def _paged_case(seed, B, K, d, ps, P, N, max_len=None):
+def _paged_case(seed, B, K, d, ps, P, N, max_len=None, lengths=None):
     """Random pool + ragged shuffled page tables; every slot gets a distinct
     length (first one is a full-page multiple, rest arbitrary — so both a
-    partially-filled and an exactly-full last page are exercised)."""
+    partially-filled and an exactly-full last page are exercised), unless
+    `lengths` gives them. Table entries past a slot's pages name the null
+    page 0, which no slot owns."""
     rng = np.random.default_rng(seed)
     pool_k = jnp.asarray(rng.normal(size=(N, K, ps, d)), jnp.float32)
     pool_v = jnp.asarray(rng.normal(size=(N, K, ps, d)), jnp.float32)
     cap = max_len or P * ps
-    lengths = rng.integers(1, cap + 1, B)
-    lengths[0] = min(ps * max(1, int(lengths[0]) // ps), cap)  # page multiple
+    if lengths is None:
+        lengths = rng.integers(1, cap + 1, B)
+        lengths[0] = min(ps * max(1, int(lengths[0]) // ps), cap)
+    lengths = np.asarray(lengths)
     pt = np.zeros((B, P), np.int64)
     pool_ids = list(range(1, N))
     rng.shuffle(pool_ids)
@@ -113,15 +117,36 @@ def _paged_case(seed, B, K, d, ps, P, N, max_len=None):
         lengths, jnp.int32)
 
 
-@pytest.mark.parametrize("B,H,K,d,ps,P,N", [
-    (2, 4, 4, 32, 8, 4, 12),       # MHA
-    (3, 8, 2, 64, 16, 3, 16),      # GQA group 4
-    (2, 8, 1, 64, 8, 6, 16),       # MQA
-    (2, 12, 3, 32, 8, 4, 12),      # non-pow2 heads
-])
+# Rows with `lengths` pin the block walk of the kernel: `pages_per_block`
+# gives 8 pages of 16 rows (128 rows) per block for dsr1d-qwen-1.5b's
+# 12 / 2 heads of 128 and 4 pages (64 rows) for GPT-2 XL's 25 heads of 64;
+# a P that is no multiple of it leaves a last block that runs past the
+# table. Length 0 is a slot the engine left inactive.
+PAGED_ROWS = [
+    ((2, 4, 4, 32, 8, 4, 12, None), "2-4-4-32-8-4-12"),       # MHA
+    ((3, 8, 2, 64, 16, 3, 16, None), "3-8-2-64-16-3-16"),     # GQA group 4
+    ((2, 8, 1, 64, 8, 6, 16, None), "2-8-1-64-8-6-16"),       # MQA
+    ((2, 12, 3, 32, 8, 4, 12, None), "2-12-3-32-8-4-12"),     # non-pow2 heads
+    # dsr1d-shaped, P 20 = 2.5 blocks: on a block boundary, one row past
+    # it, one row, and a slot at full capacity (the clamped last block)
+    ((4, 12, 2, 128, 16, 20, 48, (128, 129, 1, 320)), "dsr1d-boundaries"),
+    # dsr1d-shaped: inside the partial last block, two rows, inactive
+    ((3, 12, 2, 128, 16, 20, 48, (300, 2, 0)), "dsr1d-tail-inactive"),
+    # GPT-2-XL-shaped (group 1, head_dim 64), P 10 = 2.5 blocks
+    ((5, 25, 25, 64, 16, 10, 40, (64, 65, 1, 160, 0)), "gpt2xl-shaped"),
+]
+
+
+@pytest.mark.parametrize("B,H,K,d,ps,P,N,lens", [r for r, _ in PAGED_ROWS],
+                         ids=[i for _, i in PAGED_ROWS])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_gqa_decode_sweep(B, H, K, d, ps, P, N, dtype):
-    pool_k, pool_v, pt, lengths = _paged_case(10 + B, B, K, d, ps, P, N)
+def test_paged_gqa_decode_sweep(B, H, K, d, ps, P, N, lens, dtype):
+    pool_k, pool_v, pt, lengths = _paged_case(10 + B, B, K, d, ps, P, N,
+                                              lengths=lens)
+    if lens is not None:
+        # garbage in the null page that every table tail names
+        pool_k = pool_k.at[0].set(99.0)
+        pool_v = pool_v.at[0].set(-99.0)
     q = _rand(jax.random.PRNGKey(B), (B, H, d), dtype)
     pool_k, pool_v = pool_k.astype(dtype), pool_v.astype(dtype)
     out = paged_gqa_decode(q, pool_k, pool_v, pt, lengths,

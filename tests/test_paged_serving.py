@@ -189,3 +189,59 @@ def test_admission_blocks_until_pages_available(small):
     assert len(done) == 2
     assert cb.stats.peak_active_slots == 1
     assert cb.ledger.allocator.n_allocated == 0
+
+
+@pytest.mark.parametrize("kv_dtype", ["native", "fp8", "int8"])
+def test_attn_block_counters_match_kernel_lengths(small, monkeypatch,
+                                                  kv_dtype):
+    """`attn_blocks_live` counts the paged kernel's compute blocks that hold
+    a live row, summed over decode steps, and `attn_blocks_total` the
+    blocks of its grid. Both must equal a brute-force count: from the
+    lengths the kernel is handed at every step (recorded as it runs) and
+    from each request's prompt and output lengths. The mix retires a slot
+    mid-chunk, admits into a freed slot and crosses a block boundary. int8
+    pools go through the one-page kernel and count nothing."""
+    import repro.kernels.paged_gqa_decode as pgd
+    cfg, m, params = small
+    seen = []
+    inner = pgd.paged_gqa_decode
+
+    def recording(q, kp, vp, pt, lengths, **kw):
+        jax.debug.callback(lambda x: seen.append(np.asarray(x)), lengths)
+        return inner(q, kp, vp, pt, lengths, **kw)
+
+    monkeypatch.setattr(pgd, "paged_gqa_decode", recording)
+    ps, P, slots, steps = 8, 24, 3, 4
+    ppb = pgd.pages_per_block(cfg.num_kv_heads, ps, cfg.head_dim, P)
+    rows, nblk = ppb * ps, -(-P // ppb)
+    assert nblk == 2 and P % ppb
+    cb = _batcher(m, params, num_slots=slots, page_size=ps, num_pages=80,
+                  max_pages_per_slot=P, chunk_steps=steps, kv_dtype=kv_dtype)
+    rng = np.random.default_rng(3)
+    lens = [(120, 11), (126, 6), (60, 9), (40, 7)]
+    for i, (S, n) in enumerate(lens):
+        cb.submit(Request(rid=i, tokens=rng.integers(0, cfg.vocab_size, S),
+                          max_new_tokens=n))
+    done = cb.run()
+    assert len(done) == len(lens)
+    st = cb.stats
+    if kv_dtype == "int8":
+        assert not seen and st.chunks > 0
+        assert st.attn_blocks_live == st.attn_blocks_total == 0
+        return
+
+    def blocks(L):
+        return -(-np.asarray(L) // rows)
+
+    # a request of prompt S and n tokens decodes n - 1 steps over S + 1 ..
+    # S + n - 1 rows (its first token comes from prefill)
+    per_request = sum(int(blocks(np.arange(len(r.tokens) + 1,
+                                           len(r.tokens) + len(r.output))
+                                 ).sum()) for r in done)
+    layers = cfg.num_layers
+    assert len(seen) == st.chunks * steps * layers
+    from_kernel = sum(int(blocks(L).sum()) for L in seen)
+    assert from_kernel % layers == 0
+    assert st.attn_blocks_live == per_request == from_kernel // layers
+    assert st.attn_blocks_total == st.chunks * steps * slots * nblk
+    assert st.decode_steps == sum(len(r.output) - 1 for r in done)

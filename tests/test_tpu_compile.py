@@ -59,17 +59,24 @@ def _assert_kernel(compiled):
 # (H, K, head_dim): dsr1d-qwen-1.5b GQA, and GPT-2 XL MHA at head_dim 64
 DECODE_WIDTHS = {"dsr1d": (12, 2, 128), "gpt2xl": (25, 25, 64)}
 B, PS, N_PAGES, P = 16, 16, 4096, 256
+# widths, then (slots, pages per slot, pool pages): the rehearsal sizes,
+# and the engines of the benchmark's backlog cells
+DECODE_CASES = {"dsr1d": ("dsr1d", B, P, N_PAGES),
+                "gpt2xl": ("gpt2xl", B, P, N_PAGES),
+                "dsr1d-cell": ("dsr1d", 64, 160, 10240),
+                "gpt2xl-cell": ("gpt2xl", 16, 64, 384)}
 
 
-@pytest.mark.parametrize("widths", sorted(DECODE_WIDTHS))
-def test_paged_decode_compiles(one_chip, widths):
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_paged_decode_compiles(one_chip, case):
     from repro.kernels.paged_gqa_decode import paged_gqa_decode
+    widths, b, p, n = DECODE_CASES[case]
     H, K, d = DECODE_WIDTHS[widths]
     s = functools.partial(_spec, one_chip)
-    pool = s((N_PAGES, K, PS, d), jnp.bfloat16)
+    pool = s((n, K, PS, d), jnp.bfloat16)
     _assert_kernel(paged_gqa_decode.lower(
-        s((B, H, d), jnp.bfloat16), pool, pool, s((B, P), jnp.int32),
-        s((B,), jnp.int32), backend="pallas").compile())
+        s((b, H, d), jnp.bfloat16), pool, pool, s((b, p), jnp.int32),
+        s((b,), jnp.int32), backend="pallas").compile())
 
 
 def test_paged_decode_quant_compiles(one_chip):
